@@ -72,7 +72,11 @@ def child_build(n: int, chunk: int, d: int, path: str, page_rows: int) -> None:
 
 
 def run_build(n: int, chunk: int, d: int, path: str, page_rows: int) -> dict:
-    env = dict(os.environ, PYTHONPATH=os.environ.get("PYTHONPATH", "src"))
+    # the build is host-only: the child must not claim an accelerator
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        p for p in (src, os.environ.get("PYTHONPATH")) if p))
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--n", str(n), "--chunk", str(chunk), "--d", str(d),

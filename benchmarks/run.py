@@ -39,6 +39,8 @@ def stamp_envelopes(pattern: str = "BENCH_*.json") -> list:
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (bench_ablation, bench_learning_size, bench_query_perf,
                    bench_scale, bench_selectivity_scale_aspect,
                    bench_serve_engine, bench_serving, bench_smbo,

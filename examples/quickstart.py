@@ -10,6 +10,7 @@ import time
 import numpy as np
 
 from repro.api import Count, Database, Knn, Point, Range
+from repro.compile_cache import use_compile_cache
 from repro.baselines.zm import build_zm_index
 from repro.core.query import (brute_force_count, brute_force_knn,
                               brute_force_range, run_workload)
@@ -19,6 +20,7 @@ from repro.data.workload import make_workload
 
 
 def main():
+    use_compile_cache()
     print("== LMSFC quickstart ==")
     data = make_dataset("osm", 30_000, seed=0)
     K = default_K(2)

@@ -10,6 +10,7 @@ import threading
 import numpy as np
 
 from repro.api import Count, Database, Knn, Point, Range
+from repro.compile_cache import use_compile_cache
 from repro.core.theta import default_K
 from repro.data.synth import make_dataset
 from repro.data.workload import make_workload
@@ -20,6 +21,7 @@ PER_CLIENT = 20
 
 
 def main():
+    use_compile_cache()
     print("== async serving demo ==")
     data = make_dataset("osm", 20_000, seed=0)
     K = default_K(2)

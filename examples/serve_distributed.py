@@ -23,16 +23,18 @@ def main():
         + os.environ.get("XLA_FLAGS", ""))
     import time
 
-    import jax
     import numpy as np
 
     from repro.api import Database, EngineConfig
+    from repro.compile_cache import use_compile_cache
     from repro.core.index import IndexConfig
     from repro.core.query import brute_force_count
     from repro.core.theta import default_K
     from repro.data.synth import make_dataset
     from repro.data.workload import make_workload
+    from repro.dist.compat import make_mesh
 
+    use_compile_cache()
     data = make_dataset("osm", args.n, seed=0)
     K = default_K(2)
     Ls_tr, Us_tr = make_workload(data, 80, seed=1, K=K)
@@ -41,7 +43,7 @@ def main():
                       smbo=dict(max_iters=3, n_init=5, evals_per_iter=2))
 
     d, m = (args.devices // 2, 2) if args.devices > 1 else (1, 1)
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     db.engine("distributed", EngineConfig(mesh=mesh, max_cand=256,
                                           q_chunk=16))
     print(f"serving on {args.devices} devices, {db.num_pages} pages "

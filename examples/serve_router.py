@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.api import (Count, Database, EngineConfig, Knn, Point, Range,
                        Router)
+from repro.compile_cache import use_compile_cache
 from repro.core.index import IndexConfig
 from repro.core.theta import default_K
 from repro.data.synth import make_dataset
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--n", type=int, default=30_000)
     ap.add_argument("--n-q", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     data = make_dataset("osm", args.n, seed=0)
     K = default_K(2)

@@ -5,6 +5,7 @@ pipeline, checkpoint/restart, and the FT supervisor.
     PYTHONPATH=src python examples/train_lm_indexed.py [--steps 30]
 """
 import argparse
+import os
 import shutil
 import subprocess
 import sys
@@ -21,18 +22,21 @@ def main():
     base = [sys.executable, "-m", "repro.launch.train", "--arch", args.arch,
             "--reduced", "--batch", "8", "--seq", "128",
             "--ckpt-dir", ckpt, "--ckpt-every", "10"]
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
+    # a reduced-config CPU demo: the children run on the host backend
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
 
     half = max(10, args.steps // 2)
     print(f"phase 1: train {half} steps (checkpoint every 10)...")
     r1 = subprocess.run(base + ["--steps", str(half)], env=env,
-                        cwd=".", capture_output=True, text=True)
+                        cwd=root, capture_output=True, text=True)
     print(r1.stdout[-1500:])
     assert r1.returncode == 0, r1.stderr[-2000:]
 
     print(f"phase 2: resume from checkpoint, continue to {args.steps}...")
     r2 = subprocess.run(base + ["--steps", str(args.steps), "--resume"],
-                        env=env, cwd=".", capture_output=True, text=True)
+                        env=env, cwd=root, capture_output=True, text=True)
     print(r2.stdout[-1500:])
     assert r2.returncode == 0, r2.stderr[-2000:]
     assert "resumed from step" in r2.stdout

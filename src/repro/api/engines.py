@@ -392,7 +392,8 @@ class DistributedEngine(_DeviceEngine):
             return self.cfg.mesh
         if self._mesh is None:
             import jax
-            self._mesh = jax.make_mesh((jax.device_count(),), ("pages",))
+            from ..dist.compat import make_mesh
+            self._mesh = make_mesh((jax.device_count(),), ("pages",))
         return self._mesh
 
     @property

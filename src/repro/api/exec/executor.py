@@ -48,12 +48,11 @@ class CacheStats:
 def _fence(out):
     """Block until `out`'s device buffers are actually materialized, so a
     span around a compiled-fn launch measures real device time instead of
-    async dispatch latency.  Numpy pytrees pass through untouched."""
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:       # fencing is best-effort; results are untouched
-        pass
+    async dispatch latency.  Leaves that are not JAX arrays (numpy) are
+    skipped; a device error raises here."""
+    import jax
+    jax.block_until_ready([x for x in jax.tree.leaves(out)
+                           if isinstance(x, jax.Array)])
 
 
 def _concat_rows(parts, d, dist_parts=None):

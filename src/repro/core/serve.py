@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..dist.compat import shard_map
 from ..kernels.window_filter.ops import window_filter, window_match
 from .curve import as_curve
 from .index import LMSFCIndex
@@ -431,9 +430,9 @@ def make_distributed_query_fn(curve, mesh, *, k_maxsplit: int = 4,
     shard_specs = ServingArrays(
         points=P(axes), page_zmin=P(axes), page_zmax=P(axes),
         page_mbr=P(axes), page_size=P(axes))
-    f = shard_map(_local, mesh=mesh,
-                  in_specs=(shard_specs, P()),
-                  out_specs=(P(), P()))
+    f = jax.shard_map(_local, mesh=mesh,
+                      in_specs=(shard_specs, P()),
+                      out_specs=(P(), P()))
     return f, shard_specs
 
 

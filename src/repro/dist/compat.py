@@ -1,23 +1,19 @@
-"""JAX version compatibility for the distribution layer.
+"""Mesh construction for the installed JAX (0.9).
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-(renaming ``check_rep`` -> ``check_vma`` along the way); this wrapper accepts
-the modern spelling and degrades to the experimental API on older jax.
+`make_mesh` is the one place meshes are built: ``jax.make_mesh`` now
+defaults every axis to ``AxisType.Explicit``, which types shardings into
+every array and rejects the compiler-propagated (``Auto``) programs this
+code is written as.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    kw = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler).  `devices` defaults to all visible devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)

@@ -81,6 +81,8 @@ _OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
 _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _CALL_ATTR_RE = re.compile(r"(?:to_apply|calls)=%?([\w.\-]+)")
 _INT_RE = re.compile(r"-?\d+")
+# the trip count XLA proved for a while loop, in its backend_config
+_KNOWN_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 
 _COLLECTIVES = {"all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute", "collective-broadcast"}
@@ -251,8 +253,9 @@ class HloAnalyzer:
         return n * (g - 1) / g
 
     def _trip_count(self, cond_comp: str) -> int:
-        """Trip count of a while loop from its condition computation: find
-        the ROOT compare against a constant (counting loops emitted by
+        """Trip count of a while loop whose op carries no
+        ``known_trip_count``, from its condition computation: find the
+        compare against a constant (counting loops emitted by
         lax.scan / fori_loop compare an induction var with direction LT/LE).
         Unknown patterns conservatively report 1."""
         consts = {}
@@ -339,7 +342,9 @@ class HloAnalyzer:
             if oc == "while":
                 cm = re.search(r"condition=%?([\w.\-]+)", op.rest)
                 bm = re.search(r"body=%?([\w.\-]+)", op.rest)
-                trip = self._trip_count(cm.group(1)) if cm else 1
+                known = _KNOWN_TRIP_RE.search(op.rest)
+                trip = (int(known.group(1)) if known else
+                        self._trip_count(cm.group(1)) if cm else 1)
                 if bm:
                     absorb(self._comp_cost(bm.group(1)), trip)
                 continue

@@ -20,11 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:
-    _CompilerParams = pltpu.CompilerParams
-except AttributeError:  # renamed from TPUCompilerParams after jax 0.4.x
-    _CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -115,7 +110,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.curve import GlobalTheta, PiecewiseCurve, as_curve
 
@@ -130,8 +131,9 @@ def _encode_pool_kernel(x_ref, pos_ref, reg_ref, out_ref):
     """x_ref: (d, block_n) int32 — shared point block;
     pos_ref: (1, R, T) int32 — this candidate's output-position table
     (region r, flat input bit t = dim*K + bit), rows past the real region
-    count repeat row 0; reg_ref: (1, M) int32 — flat indexes of the region
-    bits (sentinel T = always-zero); out_ref: (1, 2, block_n) int32 Z64.
+    count repeat row 0; reg_ref: (P, M) int32 in SMEM — the whole pool's
+    flat region-bit indexes (sentinel T = always-zero), read as scalars at
+    row `program_id(0)`; out_ref: (1, 2, block_n) int32 Z64.
 
     Unlike the static bodies above, the curve arrives as *data*, so the
     shift amounts are traced values: bit planes are built once (static
@@ -142,6 +144,7 @@ def _encode_pool_kernel(x_ref, pos_ref, reg_ref, out_ref):
     d, N = x_ref.shape
     R, T = pos_ref.shape[1], pos_ref.shape[2]
     M = reg_ref.shape[1]
+    p = pl.program_id(0)
     K = T // d
     # bit planes, (T, N): plane t = i*K + j holds bit j of dimension i
     planes = [((x_ref[i, :][None, :] >>
@@ -152,7 +155,7 @@ def _encode_pool_kernel(x_ref, pos_ref, reg_ref, out_ref):
     # region code from the M (possibly sentinel) region-bit indexes
     r = jnp.zeros((N,), jnp.int32)
     for m in range(M):
-        bm = jnp.where(tidx == reg_ref[0, m], bits, 0).sum(axis=0)
+        bm = jnp.where(tidx == reg_ref[p, m], bits, 0).sum(axis=0)
         r = r | (bm << np.int32(m))
     # per-region variable-shift placement, merged by region mask
     hi = jnp.zeros((N,), jnp.int32)
@@ -178,14 +181,13 @@ def sfc_encode_pool_dn(x_dn, pos, reg, block_n: int = 2048,
     Z64 — every candidate curve's encode of the same points, one launch."""
     d, n = x_dn.shape
     P, R, T = pos.shape
-    M = reg.shape[1]
     assert n % block_n == 0, "caller pads n to a block multiple"
     return pl.pallas_call(
         _encode_pool_kernel,
         grid=(P, n // block_n),
         in_specs=[pl.BlockSpec((d, block_n), lambda p, i: (0, i)),
                   pl.BlockSpec((1, R, T), lambda p, i: (p, 0, 0)),
-                  pl.BlockSpec((1, M), lambda p, i: (p, 0))],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=pl.BlockSpec((1, 2, block_n), lambda p, i: (p, 0, i)),
         out_shape=jax.ShapeDtypeStruct((P, 2, n), jnp.int32),
         interpret=interpret,

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..dist.compat import shard_map
 from .common import dense
 
 
@@ -219,9 +218,9 @@ def moe_ffn_shardmap(p, cfg, x, mesh, rules, *, capacity_factor: float = 1.25):
             y = jax.lax.psum(y, tp_ax)
         return y.astype(xl.dtype).reshape(Bl, S, D), drop[None]
 
-    f = shard_map(local_fn, mesh=mesh,
-                  in_specs=(wspec, P(axes, None, None)),
-                  out_specs=(P(axes, None, None), P(axes)),
-                  check_vma=False)
+    f = jax.shard_map(local_fn, mesh=mesh,
+                      in_specs=(wspec, P(axes, None, None)),
+                      out_specs=(P(axes, None, None), P(axes)),
+                      check_vma=False)
     y, drop = f(p, x)
     return y, jnp.mean(drop)

@@ -1,7 +1,5 @@
 """Loop-aware HLO analyzer: exact flops on a known scan+grad program, and
 regression guards on the parser primitives."""
-import subprocess
-import sys
 import textwrap
 
 from repro.dist.hlo_analysis import (HloAnalyzer, _shape_bytes,
@@ -20,13 +18,12 @@ def test_shape_bytes():
     assert _shape_bytes("replica_groups=[2,4]<=[8]") == 0
 
 
-def test_analyzer_counts_scan_trip_counts():
+def test_analyzer_counts_scan_trip_counts(cpu_child):
     """6-layer scan + grad: exactly 3 dots of 2*4*128*32 flops per layer."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.dist.compat import make_mesh
         from repro.dist.hlo_analysis import analyze_hlo_text
 
         def step(params, x):
@@ -35,7 +32,7 @@ def test_analyzer_counts_scan_trip_counts():
             h, _ = jax.lax.scan(body, x, params)
             return h.sum()
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ps = jax.ShapeDtypeStruct((6, 128, 128), jnp.float32,
                                   sharding=NamedSharding(mesh, P(None, None, "model")))
         xs = jax.ShapeDtypeStruct((8, 128), jnp.float32,
@@ -45,15 +42,11 @@ def test_analyzer_counts_scan_trip_counts():
         assert res["flops"] == 6 * 3 * (2 * 4 * 128 * 32), res["flops"]
         assert res["bytes"] > 0 and res["bytes_unfused"] >= res["bytes"]
         assert res["collectives"]["all-gather"]["count"] == 12
-        ca = comp.cost_analysis()  # list of per-device dicts on jax<=0.4.x
-        xla = (ca[0] if isinstance(ca, list) else ca)["flops"]
+        xla = comp.cost_analysis()["flops"]
         assert res["flops"] > 3 * xla  # XLA undercounts loop bodies
         print("OK-ANALYZER")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd="/root/repo",
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"}, timeout=600)
+    r = cpu_child(code)
     assert "OK-ANALYZER" in r.stdout, r.stderr[-2000:]
 
 

@@ -1,6 +1,4 @@
 """TPU-vectorized serving engine vs brute force (+ distributed shard_map)."""
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -15,6 +13,7 @@ from repro.core.serve import (build_serving_arrays, make_distributed_query_fn,
                               make_query_fn, shard_serving_arrays)
 from repro.core.theta import default_K, random_theta
 from repro.data.synth import make_dataset
+from repro.dist.compat import make_mesh
 from repro.data.workload import make_workload
 
 
@@ -60,7 +59,7 @@ def test_overflow_flag_when_cand_bound_too_small():
 
 def test_distributed_engine_single_device_mesh():
     data, idx, theta, q, want, wl = _setup("nyc")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     arrays = build_serving_arrays(idx, pad_pages_to=1)
     arrays = shard_serving_arrays(arrays, mesh)
     fn, _ = make_distributed_query_fn(theta, mesh,
@@ -85,11 +84,9 @@ def test_facade_routes_same_engine_exactly():
     np.testing.assert_array_equal(res.counts, want)
 
 
-def test_distributed_engine_8_devices():
+def test_distributed_engine_8_devices(cpu_child):
     """Page-sharded serving on a 4x2 fake-device mesh: exact counts + psum."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, jax.numpy as jnp
         from repro.core.index import IndexConfig, LMSFCIndex
         from repro.core.query import brute_force_count
@@ -99,6 +96,7 @@ def test_distributed_engine_8_devices():
         from repro.core.theta import default_K, random_theta
         from repro.data.synth import make_dataset
         from repro.data.workload import make_workload
+        from repro.dist.compat import make_mesh
 
         assert jax.device_count() == 8
         data = make_dataset("osm", 4000, seed=1)
@@ -109,7 +107,7 @@ def test_distributed_engine_8_devices():
                                cfg=IndexConfig(paging="heuristic",
                                                page_bytes=2048),
                                workload=(Ls, Us), K=K)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         arrays = shard_serving_arrays(
             build_serving_arrays(idx, pad_pages_to=8), mesh)
         fn, _ = make_distributed_query_fn(theta, mesh,
@@ -121,27 +119,23 @@ def test_distributed_engine_8_devices():
         np.testing.assert_array_equal(np.asarray(counts), want)
         print("OK-8DEV")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                                        "HOME": "/root"}, cwd="/root/repo",
-                       timeout=600)
+    r = cpu_child(code)
     assert "OK-8DEV" in r.stdout, r.stderr[-3000:]
 
 
-def test_moe_shardmap_matches_global_dispatch():
+def test_moe_shardmap_matches_global_dispatch(cpu_child):
     """Fully-manual shard_map MoE == global-dispatch MoE (8 fake devices).
     Capacity semantics differ (per-shard), so use capacity ample enough
     that nothing is dropped in either variant."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs.registry import get_arch, reduced_config
+        from repro.dist.compat import make_mesh
         from repro.dist.sharding import ShardingRules
         from repro.models.moe import init_moe, moe_ffn, moe_ffn_shardmap
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = ShardingRules(model_size=2, data_size=4, fsdp=True)
         cfg = dataclasses.replace(
             reduced_config(get_arch("granite-moe-3b-a800m")),
@@ -162,8 +156,5 @@ def test_moe_shardmap_matches_global_dispatch():
                                    atol=3e-2, rtol=3e-2)
         print("OK-MOE-SHARDMAP")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd="/root/repo",
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"}, timeout=600)
+    r = cpu_child(code)
     assert "OK-MOE-SHARDMAP" in r.stdout, r.stderr[-3000:]

@@ -1,8 +1,6 @@
 """Optimizer, checkpoint (incl. elastic restore onto a different mesh),
 gradient compression, FT supervisor, data pipeline."""
 import os
-import subprocess
-import sys
 import textwrap
 
 import jax
@@ -88,32 +86,28 @@ def test_checkpoint_roundtrip_and_keep_k(tmp_path):
         np.asarray(a, np.float32), np.asarray(b, np.float32)), tree, restored)
 
 
-def test_checkpoint_elastic_restore_different_mesh():
+def test_checkpoint_elastic_restore_different_mesh(cpu_child):
     """Save on a (4,2) mesh, restore onto (2,2) — subprocess w/ 8 devices."""
     code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.checkpoint import save_checkpoint, restore_checkpoint
+        from repro.dist.compat import make_mesh
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         w = jnp.arange(64.0 * 32).reshape(64, 32)
         wa = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
         d = tempfile.mkdtemp()
         save_checkpoint(d, 10, {"w": wa})
 
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         shard_b = {"w": NamedSharding(mesh_b, P("model", "data"))}
         restored, _ = restore_checkpoint(d, 10, {"w": wa}, shard_b)
         np.testing.assert_array_equal(np.asarray(restored["w"]), np.asarray(w))
         assert restored["w"].sharding.mesh.shape["data"] == 2
         print("OK-ELASTIC")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd="/root/repo",
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"}, timeout=600)
+    r = cpu_child(code)
     assert "OK-ELASTIC" in r.stdout, r.stderr[-2000:]
 
 
