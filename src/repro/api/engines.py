@@ -51,6 +51,19 @@ class StaleServingError(RuntimeError):
     was configured with ``on_stale='error'``."""
 
 
+def fetch(kind: str, *outs) -> list:
+    """A compiled query fn's device outputs as host numpy arrays: the
+    device-to-host copies, timed as the ``executor.fetch`` span and
+    counted in ``executor.fetch_bytes`` (`kind`: the program, 'count' or
+    'range')."""
+    with obs.span("executor.fetch", kind=kind):
+        host = [np.asarray(x) for x in outs]
+    if obs.enabled():
+        obs.inc("executor.fetch_bytes", sum(h.nbytes for h in host),
+                kind=kind)
+    return host
+
+
 def register_engine(name: str):
     def deco(cls):
         _ENGINES[name] = cls
@@ -299,9 +312,9 @@ class _DeviceEngine(BaseEngine):
         Q = len(Ls)
         q = self._device_queries(Ls, Us)
         fn = self.db.executor.count_fn(self, max_cand or self.cfg.max_cand)
-        counts, over = fn(self._arrays, q)
-        return (np.asarray(counts)[:Q].astype(np.int64),
-                np.asarray(over)[:Q].astype(np.int32), None)
+        counts, over = fetch("count", *fn(self._arrays, q))
+        return (counts[:Q].astype(np.int64), over[:Q].astype(np.int32),
+                None)
 
     def run_range(self, Ls, Us, max_cand=None, max_hits=None):
         if len(Ls) == 0:      # nothing to pad or launch (off-bucket shape)
@@ -321,18 +334,20 @@ class _DeviceEngine(BaseEngine):
         fn = self.db.executor.range_fn(
             self, max_cand or self.cfg.max_cand,
             max_hits or self.cfg.max_hits)
-        ids, n_hits, co, ho = fn(self._arrays, q)
-        ids = np.asarray(ids)[:Q]
-        co = np.asarray(co)[:Q].astype(np.int32)
-        ho = np.asarray(ho)[:Q].astype(np.int32)
+        ids, _, co, ho = fn(self._arrays, q)
+        ids, co, ho = fetch("range", ids, co, ho)
+        ids = ids[:Q]
+        co = co[:Q].astype(np.int32)
+        ho = ho[:Q].astype(np.int32)
         # resolve global row ids (page * cap + slot) against the host copy
-        pts_u32 = np.ascontiguousarray(self._host.points).view(np.uint32)
-        cap = pts_u32.shape[2]
-        rows_list = []
-        for i in range(Q):
-            gid = ids[i][ids[i] >= 0].astype(np.int64)
-            rows_list.append(
-                pts_u32[gid // cap, :, gid % cap].astype(np.uint64))
+        with obs.span("executor.resolve", kind="range", engine=self.name):
+            pts_u32 = np.ascontiguousarray(self._host.points).view(np.uint32)
+            cap = pts_u32.shape[2]
+            rows_list = []
+            for i in range(Q):
+                gid = ids[i][ids[i] >= 0].astype(np.int64)
+                rows_list.append(
+                    pts_u32[gid // cap, :, gid % cap].astype(np.uint64))
         return rows_list, co, ho, None
 
 

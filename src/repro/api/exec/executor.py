@@ -123,6 +123,8 @@ class Executor:
                           kind=key[1]):
                 inner = build()
 
+            budgets = dict(zip(("max_cand", "max_hits"), key[2:]))
+
             def fn(arrays, queries, _key=key, _inner=inner, _eng=eng_name):
                 self.cache.calls += 1
                 tk = (_key, tuple(queries.shape),
@@ -135,12 +137,17 @@ class Executor:
                     return _inner(arrays, queries)
                 # first launch of a (fn, shape) combo includes the XLA
                 # trace+compile, so it books under stage='compile', not
-                # the device stages; the fence makes device time real
+                # the device stages; the fence makes device time real,
+                # and its own span tells waiting apart from dispatching
                 stage = "compile" if new_trace else self._stage
                 with obs.span("executor.device_call", engine=_eng,
-                              kind=_key[1], stage=stage):
-                    out = _inner(arrays, queries)
-                    _fence(out)
+                              kind=_key[1], stage=stage, **budgets):
+                    with obs.span("executor.dispatch", engine=_eng,
+                                  kind=_key[1]):
+                        out = _inner(arrays, queries)
+                    with obs.span("executor.fence", engine=_eng,
+                                  kind=_key[1]):
+                        _fence(out)
                 return out
 
             self._fns[key] = fn
@@ -327,8 +334,9 @@ class Executor:
         else:
             rows_list, first_over, over, rounds, fallbacks, stats = \
                 self._range_exact(plan, eng, Ls, Us)
-        rows_list = [lex_sorted_rows(r) for r in rows_list]  # canonical order
-        rows, offsets = _concat_rows(rows_list, self.db.d)
+        with obs.span("executor.sort", kind=plan.kind, engine=name):
+            rows_list = [lex_sorted_rows(r) for r in rows_list]  # canonical
+            rows, offsets = _concat_rows(rows_list, self.db.d)
         if stats is None:
             stats = QueryStats(result=int(offsets[-1]), subqueries=len(Ls))
         return RangeResult(rows=rows, offsets=offsets, engine=name,
@@ -382,7 +390,6 @@ class Executor:
                              k=k, metric=metric, engine=name, epoch=epoch,
                              stats=stats, plan=plan)
         eng.sync(eng.cfg.on_stale)
-        radius = eng.knn_radius(centers, k, metric)
         total = eng.live_row_total()
         kk = min(k, total)
         if kk <= 0:
@@ -391,18 +398,22 @@ class Executor:
             return KnnResult(neighbors=rows, offsets=offsets, dists=dd,
                              k=k, metric=metric, engine=name, epoch=epoch,
                              plan=plan)
-        Ls = np.empty_like(centers)
-        Us = np.empty_like(centers)
-        for i, (c, r) in enumerate(zip(centers, radius)):
-            Ls[i], Us[i] = knn_box(c, r, db.index.K)
+        with obs.span("executor.knn_seed", kind=plan.kind, engine=name):
+            radius = eng.knn_radius(centers, k, metric)
+            Ls = np.empty_like(centers)
+            Us = np.empty_like(centers)
+            for i, (c, r) in enumerate(zip(centers, radius)):
+                Ls[i], Us[i] = knn_box(c, r, db.index.K)
         rows_list, _, _, rounds, fallbacks, stats = \
             self._range_exact(plan, eng, Ls, Us)
-        parts, dist_parts = [], []
-        for c, rows in zip(centers, rows_list):
-            sel, dd = knn_select(rows, c, kk, metric)
-            parts.append(sel)
-            dist_parts.append(dd)
-        rows, offsets, dd = _concat_rows(parts, db.d, dist_parts)
+        with obs.span("executor.knn_select", kind=plan.kind, engine=name):
+            parts, dist_parts = [], []
+            for c, rows in zip(centers, rows_list):
+                sel, dd = knn_select(rows, c, kk, metric)
+                parts.append(sel)
+                dist_parts.append(dd)
+        with obs.span("executor.sort", kind=plan.kind, engine=name):
+            rows, offsets, dd = _concat_rows(parts, db.d, dist_parts)
         return KnnResult(neighbors=rows, offsets=offsets, dists=dd, k=k,
                          metric=metric, engine=name, epoch=epoch,
                          stats=stats, escalations=rounds,

@@ -63,7 +63,7 @@ class _Pending:
     payload: tuple            # normalized arrays ((Ls, Us) | (xs,))
     n: int                    # sub-queries this submission contributes
     ticket: "Ticket"
-    t_submit: int = 0         # obs clock at submit (0 while obs disabled)
+    t_submit: int = 0         # obs clock at admission (0 while obs off)
 
 
 class Ticket:
@@ -133,9 +133,12 @@ class Session:
         self.flush_failures = 0          # flushes that raised and requeued
 
     # ------------------------------------------------------------------
-    def submit(self, q: Query, *, client: str = None) -> Ticket:
+    def submit(self, q: Query, *, client: str = None,
+               t_admit_ns: int = 0) -> Ticket:
         """Buffer one typed query; validates (dimensionality, bounds)
-        immediately and returns a `Ticket`."""
+        immediately and returns a `Ticket`.  `t_admit_ns` is when the
+        query was admitted upstream, on the obs clock (the serving front
+        passes its admission stamp); by default, now."""
         if not isinstance(q, Query):
             raise TypeError(
                 f"Session.submit takes a typed query (Count/Range/Point/"
@@ -150,7 +153,8 @@ class Session:
             self._pending.append(_Pending(
                 seq=self._seq, client=client, key=key, kind=q.kind,
                 payload=payload, n=len(payload[0]), ticket=ticket,
-                t_submit=obs.clock_ns() if obs.enabled() else 0))
+                t_submit=t_admit_ns or (obs.clock_ns() if obs.enabled()
+                                        else 0)))
             self._seq += 1
             n_pending = len(self._pending)
         if obs.enabled():
@@ -243,9 +247,10 @@ class Session:
             t_done = obs.clock_ns()
             obs.observe("session.coalesce_size", len(ps), kind=kind)
             for p in ps:
-                # per-ticket latency: queue wait = submit -> group start,
-                # service = submit -> result resolved (both on tickets
-                # submitted while obs was on; 0-stamped ones are skipped)
+                # per-ticket latency: queue wait = admission -> group
+                # start, service = admission -> result resolved (both on
+                # tickets admitted while obs was on; 0-stamped ones are
+                # skipped)
                 if p.t_submit:
                     obs.observe("session.queue_wait_ns",
                                 t_start - p.t_submit, kind=kind)

@@ -170,6 +170,13 @@ def pack_query_rects(Ls, Us, Q_pad: int = None) -> np.ndarray:
 _SIGN = np.int32(-(2**31))
 
 
+def _named(fn, name: str):
+    """`fn` renamed, so ``jax.jit`` names its module ``jit_<name>``: each
+    kind and ladder rung of the query programs reads apart in a profile."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _u32_le(a, b):
     return (a ^ _SIGN) <= (b ^ _SIGN)
 
@@ -177,11 +184,11 @@ def _u32_le(a, b):
 def make_query_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
                   q_chunk: int = 16, backend: str = "xla",
                   interpret: bool = False):
-    """Returns query_batch(arrays, queries (Q, d, 2) int32) -> (counts (Q,),
-    overflowed (Q,) int32 overflow counts — 0/1 on a single shard, psum-
-    additive across shards in the distributed engine).  Static shapes
-    throughout; Q % q_chunk == 0.  `curve` is any `MonotonicCurve`
-    (legacy `Theta` values are coerced)."""
+    """Returns query_batch_count_c<max_cand>(arrays, queries (Q, d, 2)
+    int32) -> (counts (Q,), overflowed (Q,) int32 overflow counts — 0/1
+    on a single shard, psum-additive across shards in the distributed
+    engine).  Static shapes throughout; Q % q_chunk == 0.  `curve` is any
+    `MonotonicCurve` (legacy `Theta` values are coerced)."""
     curve = as_curve(curve)
 
     def _chunk(arrays: ServingArrays, queries):
@@ -236,7 +243,7 @@ def make_query_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
         counts, over = jax.lax.map(functools.partial(_chunk, arrays), qs)
         return counts.reshape(Q), over.reshape(Q).astype(jnp.int32)
 
-    return query_batch
+    return _named(query_batch, f"query_batch_count_c{max_cand}")
 
 
 # ---------------------------------------------------------------------------
@@ -252,7 +259,8 @@ def make_range_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
     id buffer (global row id = page * cap + slot, so the host resolves rows
     from its packed copy with one gather).
 
-    Returns query_batch(arrays, queries (Q, d, 2) int32) ->
+    Returns query_batch_range_c<max_cand>_h<max_hits>(arrays, queries
+    (Q, d, 2) int32) ->
       ids      (Q, max_hits) int32 — matching global row ids, -1 padded
       n_hits   (Q,) int32 — total matches within the candidate-page set
       cand_over (Q,) int32 — candidate pages overflowed max_cand
@@ -328,7 +336,8 @@ def make_range_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
         return (ids.reshape(Q, -1), n_hits.reshape(Q),
                 co.reshape(Q), ho.reshape(Q))
 
-    return query_batch
+    return _named(query_batch,
+                  f"query_batch_range_c{max_cand}_h{max_hits}")
 
 
 # ---------------------------------------------------------------------------

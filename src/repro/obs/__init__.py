@@ -23,6 +23,10 @@ Quickstart::
     obs.export_trace("trace.json")        # load in ui.perfetto.dev
     obs.disable(); obs.reset()
 
+While enabled, every span also opens a ``jax.profiler.TraceAnnotation``
+of its name and labels, so a ``jax.profiler`` trace taken meanwhile
+shows the program's spans on the host plane beside the device's ops.
+
 The clock is injectable for deterministic tests
 (``obs.enable(clock=fake_ns_counter)``); the default is
 ``time.perf_counter_ns``.
@@ -40,7 +44,7 @@ from .metrics import (Counter, Gauge, Histogram, Registry,
 from .trace import NULL_SPAN, Span, Tracer
 
 __all__ = [
-    "enable", "disable", "enabled", "reset", "clock_ns", "span",
+    "enable", "disable", "enabled", "reset", "clock_ns", "span", "record",
     "counter", "gauge", "histogram", "inc", "observe", "set_gauge",
     "registry", "tracer", "snapshot", "export_trace", "trace_events",
     "prometheus_text", "bench_envelope", "validate_quantiles",
@@ -91,13 +95,23 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 # the hot-path hooks (single flag check + early return while disabled)
 # ---------------------------------------------------------------------------
-def span(name: str, **labels):
+def span(name: str, parent: int = None, **labels):
     """``with obs.span("executor.device_call", engine="xla"): ...`` —
     records a nested span AND feeds the ``<name>_ns`` latency histogram;
-    a shared no-op while disabled."""
+    a shared no-op while disabled.  Its parent is the span open around it
+    on this thread, or the sid `parent` given."""
     if not _enabled:
         return NULL_SPAN
-    return tracer.span(name, **labels)
+    return tracer.span(name, parent, **labels)
+
+
+def record(name: str, t0_ns: int, t1_ns: int, parent: int = None, **labels):
+    """Book an interval whose ends were stamped with `clock_ns`, possibly
+    on two threads (admission on a client thread, service on the drain
+    thread); returns its sid, or None while disabled."""
+    if not _enabled:
+        return None
+    return tracer.record(name, t0_ns, t1_ns, parent, **labels)
 
 
 def counter(name: str, **labels) -> Counter:

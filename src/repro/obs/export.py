@@ -2,10 +2,11 @@
 snapshots, and the common BENCH_*.json envelope.
 
 `export_trace` writes the Chrome trace-event format (the ``traceEvents``
-list of balanced ``"B"``/``"E"`` duration events) that both
-``chrome://tracing`` and https://ui.perfetto.dev load directly;
-timestamps are microseconds (float) per the spec, thread lanes come from
-the recording thread, and span labels ride in ``args``.
+list of balanced ``"B"``/``"E"`` duration events, and ``"b"``/``"e"``
+async pairs for cross-thread records) that both ``chrome://tracing`` and
+https://ui.perfetto.dev load directly; timestamps are microseconds
+(float) per the spec, thread lanes come from the recording thread, and
+span labels, ``sid`` and ``parent`` ride in ``args``.
 
 `prometheus_text` renders the registry in the Prometheus exposition
 format (``name{labels} value`` with ``_count`` / ``_sum`` / ``_bucket``
@@ -25,22 +26,29 @@ def _global():
 
 
 def trace_events(tracer=None) -> list:
-    """The finished spans as a sorted, balanced B/E trace-event list."""
+    """The finished spans as a sorted trace-event list: balanced B/E
+    pairs for nested spans, async b/e pairs (keyed by sid) for the
+    intervals `obs.record` booked, whose ends may lie on two threads.
+    Every begin event carries the labels plus ``sid`` and ``parent``."""
     if tracer is None:
         _, tracer = _global()
     events = []
     for s in tracer.snapshot():
         args = {str(k): str(v) for k, v in s.labels.items()}
+        args["sid"] = s.sid
+        args["parent"] = s.parent
+        begin = {"name": s.name, "cat": "repro", "ph": "B", "pid": 1,
+                 "tid": s.tid, "ts": s.t0_ns / 1e3, "args": args}
+        end = {"name": s.name, "cat": "repro", "ph": "E", "pid": 1,
+               "tid": s.tid, "ts": s.t1_ns / 1e3}
+        if s.recorded:
+            begin["ph"], end["ph"] = "b", "e"
+            begin["id"] = end["id"] = s.sid
         # sort keys: at equal timestamps close children before parents
         # (E before B, deeper E first, shallower B first) so the event
         # stream stays properly nested for the viewer
-        events.append(((s.t0_ns, 1, s.depth),
-                       {"name": s.name, "cat": "repro", "ph": "B",
-                        "pid": 1, "tid": s.tid, "ts": s.t0_ns / 1e3,
-                        "args": args}))
-        events.append(((s.t1_ns, 0, -s.depth),
-                       {"name": s.name, "cat": "repro", "ph": "E",
-                        "pid": 1, "tid": s.tid, "ts": s.t1_ns / 1e3}))
+        events.append(((s.t0_ns, 1, s.depth), begin))
+        events.append(((s.t1_ns, 0, -s.depth), end))
     return [e for _, e in sorted(events, key=lambda kv: kv[0])]
 
 

@@ -48,14 +48,16 @@ class ServerTicket:
     raises `ServingTimeout`); a batch failed past its retry budget
     re-raises its error here."""
 
-    __slots__ = ("seq", "client", "kind", "t_submit", "t_done",
-                 "_event", "_result", "_error")
+    __slots__ = ("seq", "client", "kind", "t_submit", "t_admit_ns",
+                 "t_done", "_event", "_result", "_error")
 
-    def __init__(self, kind: str, client, t_submit: float):
+    def __init__(self, kind: str, client, t_submit: float,
+                 t_admit_ns: int = 0):
         self.seq = -1               # admission order; set under server lock
         self.client = client
         self.kind = kind
         self.t_submit = t_submit    # server clock at submit
+        self.t_admit_ns = t_admit_ns  # obs clock at submit (0: obs off)
         self.t_done = None          # server clock at resolution
         self._event = threading.Event()
         self._result = None
@@ -156,7 +158,8 @@ class AsyncServer:
                 f"AsyncServer.submit takes a typed query (Count/Range/"
                 f"Point/Knn); got {type(q).__name__}")
         q.normalized(d=self.backend.d)     # validate before admission
-        ticket = ServerTicket(q.kind, client, self._clock())
+        ticket = ServerTicket(q.kind, client, self._clock(),
+                              obs.clock_ns() if obs.enabled() else 0)
         with self._lock:
             if self._closed:
                 raise RuntimeError("AsyncServer is closed")
@@ -252,20 +255,29 @@ class AsyncServer:
                         break
                     self._work.wait(remaining)
                 batch = self.queue.pop_batch(self.slo.batch_max)
+                t_pop_ns = obs.clock_ns() if obs.enabled() else 0
                 self._space.notify_all()
             if batch:
-                self._run_batch(batch)
+                self._run_batch(batch, t_pop_ns)
 
-    def _run_batch(self, batch) -> None:
+    def _run_batch(self, batch, t_pop_ns: int) -> None:
         """Coalesce one weighted-fair batch through the Session, resolve
-        tickets, and feed the controller."""
-        pairs = [(ticket, self._session.submit(q, client=ticket.client))
+        tickets, and feed the controller.  With obs on, each ticket books
+        ``serving.wait`` (admission to `t_pop_ns`, the batch's pop) and
+        ``serving.request`` (admission to resolution), labelled with the
+        batch id its ``serving.batch`` span carries."""
+        batch_id = self.batches          # only this thread advances it
+        kinds = (",".join(sorted({t.kind for t, _ in batch}))
+                 if obs.enabled() else "")
+        pairs = [(ticket, self._session.submit(
+                     q, client=ticket.client, t_admit_ns=ticket.t_admit_ns))
                  for ticket, q in batch]
         tries = 0
         error = None
         while True:
             try:
-                with obs.span("serving.batch", size=len(batch)):
+                with obs.span("serving.batch", size=len(batch),
+                              batch=batch_id, kinds=kinds):
                     self._session.flush()
                 break
             except Exception as e:          # engine hiccup: session requeued
@@ -276,6 +288,16 @@ class AsyncServer:
                     error = e
                     break
         now = self._clock()
+        if obs.enabled() and t_pop_ns:
+            t_done_ns = obs.clock_ns()
+            for ticket, _ in batch:
+                if ticket.t_admit_ns:
+                    ids = dict(kind=ticket.kind, seq=ticket.seq,
+                               batch=batch_id)
+                    sid = obs.record("serving.request", ticket.t_admit_ns,
+                                     t_done_ns, **ids)
+                    obs.record("serving.wait", ticket.t_admit_ns, t_pop_ns,
+                               parent=sid, **ids)
         latencies_ms = []
         unresolved = []
         for ticket, st in pairs:

@@ -40,7 +40,8 @@ import math
 import numpy as np
 
 from .. import obs
-from ..api.engines import BaseEngine, StaleServingError, register_engine
+from ..api.engines import (BaseEngine, StaleServingError, fetch,
+                           register_engine)
 from ..api.result import EngineConfig
 from ..core.serve import bucket_pow2, make_query_fn, make_range_fn, \
     pack_query_rects
@@ -195,9 +196,9 @@ class StoreEngine(BaseEngine):
         arrays = self._assemble(groups)
         q = self._device_queries(Ls, Us)
         fn = self.db.executor.count_fn(self, max_cand or self.cfg.max_cand)
-        counts, over = fn(arrays, q)
-        return (np.asarray(counts)[:Q].astype(np.int64),
-                np.asarray(over)[:Q].astype(np.int32), None)
+        counts, over = fetch("count", *fn(arrays, q))
+        return (counts[:Q].astype(np.int64), over[:Q].astype(np.int32),
+                None)
 
     def run_range(self, Ls, Us, max_cand=None, max_hits=None):
         if len(Ls) == 0:
@@ -222,14 +223,16 @@ class StoreEngine(BaseEngine):
         fn = self.db.executor.range_fn(
             self, max_cand or self.cfg.max_cand,
             max_hits or self.cfg.max_hits)
-        ids, n_hits, co, ho = fn(arrays, q)
-        ids = np.asarray(ids)[:Q]
-        co = np.asarray(co)[:Q].astype(np.int32)
-        ho = np.asarray(ho)[:Q].astype(np.int32)
-        rows_list = []
-        for i in range(Q):
-            gid = ids[i][ids[i] >= 0].astype(np.int64)
-            rows_list.append(self._resolve_rows(gid, groups, cap))
+        ids, _, co, ho = fn(arrays, q)
+        ids, co, ho = fetch("range", ids, co, ho)
+        ids = ids[:Q]
+        co = co[:Q].astype(np.int32)
+        ho = ho[:Q].astype(np.int32)
+        with obs.span("executor.resolve", kind="range", engine=self.name):
+            rows_list = []
+            for i in range(Q):
+                gid = ids[i][ids[i] >= 0].astype(np.int64)
+                rows_list.append(self._resolve_rows(gid, groups, cap))
         return rows_list, co, ho, None
 
     # -- kNN seeding over the memmap ------------------------------------

@@ -132,6 +132,122 @@ def test_span_label_after_open_and_histogram_feed():
     assert h.count == 1 and h.sum == 1000
 
 
+def test_sid_and_parent_follow_nesting_per_thread():
+    tr = Tracer(clock=fake_clock())
+    out = {}
+    both_open = threading.Barrier(2)
+
+    def work(tag):
+        with tr.span("outer", kind=tag) as a:
+            both_open.wait()               # the two threads interleave
+            with tr.span("mid") as b:
+                with tr.span("leaf") as c:
+                    pass
+            with tr.span("sibling") as e:
+                pass
+        with tr.span("explicit", parent=a.sid) as f:
+            pass
+        out[tag] = (a.sid, b.sid, c.sid, e.sid, f.sid)
+    threads = [threading.Thread(target=work, args=(t,)) for t in "xy"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    spans = {s.sid: s for s in tr.snapshot()}
+    assert len(spans) == 10                     # sids are process-unique
+    for a, b, c, e, f in out.values():
+        assert spans[a].parent is None and spans[a].depth == 0
+        assert spans[b].parent == a and spans[c].parent == b
+        assert spans[e].parent == a
+        assert spans[f].parent == a and spans[f].depth == 0
+        assert len({spans[i].tid for i in (a, b, c, e, f)}) == 1
+    x, y = out.values()
+    assert spans[x[0]].tid != spans[y[0]].tid
+
+
+def test_record_spans_threads_at_depth_zero():
+    obs.enable(clock=fake_clock())
+    t_admit = obs.clock_ns()                    # stamped on this thread
+    sids = []
+
+    def drain():
+        with obs.span("serving.batch"):
+            with obs.span("executor.execute", kind="count"):
+                req = obs.record("serving.request", t_admit, obs.clock_ns(),
+                                 kind="count", seq=7)
+                sids.append(req)
+                sids.append(obs.record("serving.wait", t_admit,
+                                       obs.clock_ns(), parent=req,
+                                       kind="count", seq=7))
+    t = threading.Thread(target=drain)
+    t.start()
+    t.join()
+    spans = {s.name: s for s in obs.tracer.snapshot()}
+    req, wait = spans["serving.request"], spans["serving.wait"]
+    assert [req.sid, wait.sid] == sids
+    assert req.depth == wait.depth == 0 and req.recorded and wait.recorded
+    assert req.parent is None and wait.parent == req.sid
+    assert req.t0_ns == wait.t0_ns == t_admit
+    assert req.tid == wait.tid == spans["serving.batch"].tid
+    assert spans["executor.execute"].depth == 1
+    assert not spans["serving.batch"].recorded
+    assert obs.registry.histogram("serving.wait_ns", kind="count").count == 1
+    # the same bounded buffer and drop accounting as spans
+    obs.tracer.max_spans = len(obs.tracer)
+    assert obs.record("serving.wait", 0, 1) is not None
+    assert obs.tracer.spans_dropped == 1
+    obs.tracer.max_spans = 200_000
+
+
+def test_histogram_series_stay_bounded():
+    obs.enable(clock=fake_clock())
+    for i in range(1000):
+        with obs.span("serving.batch", size=i, seq=i, batch=i,
+                      kind="count" if i % 2 else "range"):
+            pass
+        obs.record("serving.wait", 0, i + 1, kind="point", seq=i, batch=i)
+    series = [m for m in obs.registry.metrics() if m.kind == "histogram"]
+    keys = sorted((m.name, m.labels) for m in series)
+    assert keys == [("serving.batch_ns", (("kind", "count"),)),
+                    ("serving.batch_ns", (("kind", "range"),)),
+                    ("serving.wait_ns", (("kind", "point"),))]
+    assert sum(m.count for m in series) == 2000
+    # every label stays on the span records
+    last = obs.tracer.snapshot()[-2]
+    assert last.labels == {"size": 999, "seq": 999, "batch": 999,
+                           "kind": "count"}
+
+
+def test_disabled_leaves_registry_buffer_and_profiler_empty(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_no_pytracer())
+    with obs.span("obs.never", kind="count"):
+        with obs.span("obs.never.inner"):
+            pass
+    assert obs.record("obs.never.record", 0, 1) is None
+    obs.inc("obs.never.c")
+    jax.profiler.stop_trace()
+    assert obs.registry.snapshot() == {} and len(obs.tracer) == 0
+    names = {e.name for plane in ProfileData.from_file(_xplane(tmp_path))
+             .planes for line in plane.lines for e in line.events}
+    assert not any(n.startswith("obs.never") for n in names)
+
+
+def _no_pytracer():
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def _xplane(path):
+    import glob
+    found = glob.glob(str(path / "plugins/profile/*/*.xplane.pb"))
+    assert len(found) == 1, found
+    return found[0]
+
+
 def test_span_buffer_bounded_with_drop_accounting():
     tr = Tracer(clock=fake_clock(), max_spans=3)
     for _ in range(5):
@@ -185,12 +301,28 @@ def test_trace_export_balanced_and_nested(tmp_path):
     names = [(e["name"], e["ph"]) for e in ev]
     assert names.index(("outer", "B")) < names.index(("inner", "B"))
     assert names.index(("inner", "E")) < names.index(("outer", "E"))
-    assert ev[0]["args"] == {"kind": "count"}
+    sids = {e["name"]: e["args"]["sid"] for e in ev if e["ph"] == "B"}
+    assert ev[0]["args"] == {"kind": "count", "sid": sids["outer"],
+                             "parent": None}
+    inner = next(e for e in ev if e["name"] == "inner" and e["ph"] == "B")
+    assert inner["args"]["parent"] == sids["outer"]
     assert doc["otherData"]["spans_dropped"] == 0
     # timestamps are microseconds
     assert ev[0]["ts"] == pytest.approx(ev[0]["ts"], abs=1e-9)
     tss = [e["ts"] for e in ev]
     assert tss == sorted(tss)
+
+
+def test_trace_export_writes_records_as_async_pairs():
+    obs.enable(clock=fake_clock())
+    with obs.span("serving.batch", batch=0) as b:
+        pass
+    sid = obs.record("serving.request", 500, 4500, parent=b.sid, seq=3)
+    ev = obs.trace_events()
+    assert [e["ph"] for e in ev] == ["b", "B", "E", "e"]
+    begin = ev[0]
+    assert begin["id"] == ev[-1]["id"] == sid
+    assert begin["args"] == {"seq": "3", "sid": sid, "parent": b.sid}
 
 
 def test_prometheus_text_format():
@@ -300,6 +432,102 @@ def test_instrumented_queries_bit_identical_and_metrics_flow():
     assert db.stats(format="prometheus").startswith("# TYPE")
     with pytest.raises(ValueError, match="format"):
         db.stats(format="xml")
+
+
+def test_served_spans_land_in_the_profiler_trace(tmp_path):
+    """A served workload under ``jax.profiler`` with obs on: the spans
+    sit on the host plane, nested as obs recorded them, one clock offset
+    apart; every ticket books one wait and one request span."""
+    import jax
+    from jax.profiler import ProfileData
+    from repro.serving import SLOConfig
+    db, data, (Ls, Us) = _small_db()
+    queries = [Count(Ls[:2], Us[:2]), Range(Ls[2:4], Us[2:4]),
+               Point(data[:3]), Knn(data[3:5], k=3)] * 3
+    obs.enable()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_no_pytracer())
+    with db.serve(slo=SLOConfig(window_init_ms=1.0, adaptive=False),
+                  engine="xla") as srv:
+        tickets = [srv.submit(q) for q in queries]
+        for t in tickets:
+            t.result(timeout=120)
+    jax.profiler.stop_trace()
+    spans = obs.tracer.snapshot()
+    # more requests make no new registry series once every stage ran
+    series = []
+    for _ in range(2):
+        with db.serve(slo=SLOConfig(window_init_ms=1.0, adaptive=False),
+                      engine="xla") as srv:
+            for t in [srv.submit(q) for q in queries]:
+                t.result(timeout=120)
+        series.append({(m.name, m.labels) for m in obs.registry.metrics()})
+    assert series[0] == series[1]
+    per_request = {"size", "fill", "group", "bytes", "run", "rows",
+                   "iteration", "seq", "batch"}
+    assert not {k for _, labels in series[1] for k, _ in labels} & per_request
+    obs.disable()
+    by_sid = {s.sid: s for s in spans}
+
+    def ancestors(s):
+        while s.parent is not None:
+            s = by_sid[s.parent]
+            yield s
+
+    events = {}
+    for plane in ProfileData.from_file(_xplane(tmp_path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serving.", "executor.")):
+                    events.setdefault(e.name, []).append(
+                        (plane.name, line.name, e.start_ns,
+                         e.start_ns + e.duration_ns, e))
+    matched, offsets = {}, []
+    for name in ("serving.batch", "executor.fetch", "executor.dispatch"):
+        mine = sorted((s for s in spans if s.name == name),
+                      key=lambda s: s.t0_ns)
+        theirs = sorted(events.get(name, []), key=lambda e: e[2])
+        assert mine and len(mine) == len(theirs), name
+        assert {e[0] for e in theirs} == {"/host:CPU"}
+        for s, e in zip(mine, theirs):
+            matched[s.sid] = e
+            offsets.append(s.t0_ns - e[2])
+    offset = sorted(offsets)[len(offsets) // 2]      # one offset, +-1 ms
+    assert all(abs(o - offset) <= 1e6 for o in offsets)
+    batches = {}
+    for s in spans:
+        if s.name == "serving.batch":
+            ev = matched[s.sid]
+            assert dict(ev[4].stats)["batch"] == s.labels["batch"]
+            batches[s.labels["batch"]] = ev
+    for s in spans:
+        if s.name not in ("executor.fetch", "executor.dispatch"):
+            continue
+        up = list(ancestors(s))
+        names = [u.name for u in up]
+        assert "executor.execute" in names, names
+        if s.name == "executor.dispatch":
+            assert names[0] == "executor.device_call"
+        # inside its batch's profiler event, on the same thread's line
+        batch = next(u for u in up if u.name == "serving.batch")
+        outer, inner = batches[batch.labels["batch"]], matched[s.sid]
+        assert outer[1] == inner[1]
+        assert outer[2] <= inner[2] and inner[3] <= outer[3]
+    # every ticket: one wait and one request, wait <= request
+    for t in tickets:
+        mine = [s for s in spans if s.labels.get("seq") == t.seq
+                and s.name in ("serving.wait", "serving.request")]
+        assert sorted(s.name for s in mine) == ["serving.request",
+                                                "serving.wait"]
+        wait, req = sorted(mine, key=lambda s: s.name != "serving.wait")
+        assert wait.t0_ns == req.t0_ns and wait.dur_ns <= req.dur_ns
+        assert wait.parent == req.sid and wait.labels["kind"] == t.kind
+        assert req.labels["batch"] in batches
+    for q, t in zip(queries, tickets):
+        want = db.query(q, engine="xla")
+        for f in ("counts", "rows", "found", "neighbors"):
+            if hasattr(want, f):
+                np.testing.assert_array_equal(getattr(t.result(), f),
+                                              getattr(want, f))
 
 
 def test_instrumented_router_exact_with_per_shard_accounting():

@@ -158,3 +158,31 @@ def test_moe_shardmap_matches_global_dispatch(cpu_child):
     """)
     r = cpu_child(code)
     assert "OK-MOE-SHARDMAP" in r.stdout, r.stderr[-3000:]
+
+
+@pytest.mark.parametrize("kind", ["count", "range"])
+def test_query_programs_keep_the_module_prefix(kind):
+    """Each kind and ladder rung compiles to its own module name, under
+    the prefix the benchmark's trace reduction sums as query-program
+    time (``bench/trace_reduce.QUERY_PROGRAMS``)."""
+    import os
+    import sys
+    from repro.core.serve import pack_query_rects
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from trace_reduce import QUERY_PROGRAMS
+    data = make_dataset("osm", 1000, seed=0)
+    db = Database.fit(data, K=16, learn=False,
+                      cfg=IndexConfig(paging="heuristic", page_bytes=1024))
+    _, eng = db._get_engine("xla")
+    eng.sync()
+    q = jnp.asarray(pack_query_rects(data[:16], data[:16]))
+    fn = eng._build_qfn(32) if kind == "count" else eng._build_rfn(32, 64)
+    text = fn.lower(eng._arrays, q).as_text()
+    module = text.split("module @", 1)[1].split(" ", 1)[0]
+    want = ("query_batch_count_c32" if kind == "count"
+            else "query_batch_range_c32_h64")
+    assert module == "jit_" + want
+    assert module.startswith(QUERY_PROGRAMS[0])
