@@ -15,7 +15,8 @@ The paper's per-query page walk is re-expressed as a static-shape pipeline:
                RQS' skipping pays off, mirroring the CPU engine)
   contain    — pages whose MBR ⊆ query contribute size() with *no* gather
                (the paper's containment shortcut)
-  compact    — top-C candidate page ids per query (static bound)
+  compact    — top-C candidate page ids per query (static bound), by a
+               scatter-free rank-select over 128-lane prefix counts
   gather     — only candidate pages' points (the expensive HBM term)
   filter     — points-in-rectangle count (Pallas window_filter on TPU)
 
@@ -181,6 +182,57 @@ def _u32_le(a, b):
     return (a ^ _SIGN) <= (b ^ _SIGN)
 
 
+_LANES = 128
+_DENSE = 1 << 28    # most (slot, block) pairs compared at the top level
+
+
+def _compact(mask, width: int, tags=None):
+    """Positions of the first `width` set entries of each row of `mask`
+    (R, N) bool, ascending: ``(pos (R, width) int32, -1 past the row's
+    count; n (R,) int32 set entries)``.  Given `tags` (R, ceil(N/128))
+    int32 in [0, 2**23), one per 128-lane block of a row, also returns
+    each slot's block tag (R, width).
+
+    A rank-select over a radix-128 tree of prefix counts, with gathers
+    where a scatter would go (the TPU runs a scatter about one update at
+    a time, masked updates included).  Each level cuts its rows into
+    128-lane blocks and keeps their inclusive cumsums, up to the first
+    level with few enough blocks (<= 128, or R * width * blocks <=
+    2**28) to compare every slot with all of them.  Slot j then walks
+    down: at each level it gathers one 128-lane row and counts the
+    entries <= its remaining rank.  Nothing of size (R, width, N) is
+    built.  A tag rides in bits 8.. of its level-0 row (a count there is
+    at most 128), so it costs no gather of its own."""
+    R = mask.shape[0]
+    c = mask.astype(jnp.int32)
+    levels = []
+    while True:
+        c = jnp.pad(c, ((0, 0), (0, -c.shape[1] % _LANES)))
+        cs = jnp.cumsum(c.reshape(R, -1, _LANES), axis=2)   # (R, B, 128)
+        c = cs[:, :, -1]                                    # block counts
+        levels.append(cs)
+        if c.shape[1] <= _LANES or R * width * c.shape[1] <= _DENSE:
+            break
+    if tags is not None:
+        levels[0] = levels[0] | (tags[:, :, None] << 8)
+    top = jnp.cumsum(c, axis=1)
+    n = top[:, -1]
+    k = jnp.broadcast_to(jnp.arange(width, dtype=jnp.int32), (R, width))
+    le = top[:, None, :] <= k[:, :, None]
+    b = jnp.sum(le, axis=2, dtype=jnp.int32)
+    k = k - jnp.max(jnp.where(le, top[:, None, :], 0), axis=2)
+    for lvl in reversed(range(len(levels))):
+        b = jnp.minimum(b, levels[lvl].shape[1] - 1)  # slots past n: any
+        row = jnp.take_along_axis(levels[lvl], b[:, :, None], axis=1)
+        if lvl == 0 and tags is not None:
+            tag, row = row[:, :, 0] >> 8, row & 0xFF
+        le = row <= k[:, :, None]                       # (R, W, 128)
+        k = k - jnp.max(jnp.where(le, row, 0), axis=2)
+        b = b * _LANES + jnp.sum(le, axis=2, dtype=jnp.int32)
+    pos = jnp.where(jnp.arange(width)[None, :] < n[:, None], b, -1)
+    return (pos, n) if tags is None else (pos, n, tag)
+
+
 def make_query_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
                   q_chunk: int = 16, backend: str = "xla",
                   interpret: bool = False):
@@ -213,18 +265,11 @@ def make_query_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
         partial = live & ~contained
         # ---- containment shortcut ---------------------------------------
         base = jnp.sum(jnp.where(full, arrays.page_size[None, :], 0), axis=1)
-        # ---- compact: top-C partial candidates ---------------------------
-        Pn = partial.shape[1]
-        pos = jnp.cumsum(partial, axis=1) - 1         # (Qc, P)
-        n_cand = pos[:, -1] + 1
+        # ---- compact: first C partial candidates (rank-select) -----------
+        cand, n_cand = _compact(partial, max_cand)    # (Qc, C), -1 past n
         overflow = n_cand > max_cand
-        cand = jnp.zeros((Qc, max_cand), jnp.int32)
-        qidx = jnp.broadcast_to(jnp.arange(Qc)[:, None], partial.shape)
-        pidx = jnp.broadcast_to(jnp.arange(Pn)[None, :], partial.shape)
-        okpos = partial & (pos < max_cand)
-        cand = cand.at[jnp.where(okpos, qidx, Qc), jnp.where(okpos, pos, 0)
-                       ].set(pidx, mode="drop")
-        cand_valid = jnp.arange(max_cand)[None, :] < jnp.minimum(n_cand, max_cand)[:, None]
+        cand_valid = cand >= 0
+        cand = jnp.maximum(cand, 0)                   # invalid: page 0
         # ---- gather + filter ---------------------------------------------
         pts = arrays.points[cand]                     # (Qc, C, d, cap)
         size = jnp.where(cand_valid, arrays.page_size[cand], 0)
@@ -269,12 +314,16 @@ def make_range_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
     Unlike the count path there is no containment shortcut: contained
     pages' rows must be emitted too, so every live page is a candidate.
     Exact iff both overflow flags are 0 (the Database planner escalates
-    the rest).  Assumes pages*cap < 2^31 (ids are int32).
+    the rest).  Assumes pages*cap < 2^31 (ids are int32); raises past
+    2^23 pages (the compaction tags hits with 23-bit page ids).
     """
     curve = as_curve(curve)
 
     def _chunk(arrays: ServingArrays, queries):
         Qc = queries.shape[0]
+        if arrays.page_size.shape[0] > 1 << 23:
+            raise ValueError(f"{arrays.page_size.shape[0]} pages; range "
+                             f"retrieval takes at most 2**23")
         rects, valid = recursive_split_jax(
             queries.astype(jnp.uint32), curve, k_maxsplit)
         zlo, zhi = zranges_jax(rects, curve)          # (Qc, S, 2)
@@ -289,19 +338,11 @@ def make_range_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
         mhi = arrays.page_mbr[None, :, :, 1]
         intersect = jnp.all(_u32_le(mlo, qhi) & _u32_le(qlo, mhi), -1)
         live = ov & intersect                         # (Qc, P)
-        # ---- compact: top-C candidate pages ------------------------------
-        Pn = live.shape[1]
-        pos = jnp.cumsum(live, axis=1) - 1            # (Qc, P)
-        n_cand = pos[:, -1] + 1
+        # ---- compact: first C candidate pages (rank-select) --------------
+        cand, n_cand = _compact(live, max_cand)       # (Qc, C), -1 past n
         cand_over = n_cand > max_cand
-        cand = jnp.zeros((Qc, max_cand), jnp.int32)
-        qidx = jnp.broadcast_to(jnp.arange(Qc)[:, None], live.shape)
-        pidx = jnp.broadcast_to(jnp.arange(Pn)[None, :], live.shape)
-        okpos = live & (pos < max_cand)
-        cand = cand.at[jnp.where(okpos, qidx, Qc), jnp.where(okpos, pos, 0)
-                       ].set(pidx, mode="drop")
-        cand_valid = (jnp.arange(max_cand)[None, :]
-                      < jnp.minimum(n_cand, max_cand)[:, None])
+        cand_valid = cand >= 0
+        cand = jnp.maximum(cand, 0)                   # invalid: page 0
         # ---- gather + match (index-emitting window filter) ---------------
         pts = arrays.points[cand]                     # (Qc, C, d, cap)
         size = jnp.where(cand_valid, arrays.page_size[cand], 0)
@@ -311,19 +352,16 @@ def make_range_fn(curve, *, k_maxsplit: int = 4, max_cand: int = 64,
         mask = window_match(pts.reshape(-1, d, cap), rect.reshape(-1, d, 2),
                             size.reshape(-1), backend=backend,
                             interpret=interpret)      # (Qc*C, cap) bool
-        mask = mask.reshape(Qc, max_cand * cap)
-        gid = (cand[:, :, None] * cap
-               + jnp.arange(cap, dtype=jnp.int32)[None, None, :])
-        gid = gid.reshape(Qc, max_cand * cap)
-        # ---- compact matches into the static id buffer -------------------
-        hpos = jnp.cumsum(mask, axis=1) - 1           # (Qc, C*cap)
-        n_hits = (hpos[:, -1] + 1).astype(jnp.int32)
+        # ---- compact matches into the static id buffer (rank-select) -----
+        # pages padded to whole 128-lane blocks, each tagged with its page
+        capp = -(-cap // _LANES) * _LANES
+        mask = jnp.pad(mask.reshape(Qc, max_cand, cap),
+                       ((0, 0), (0, 0), (0, capp - cap)))
+        hpos, n_hits, page = _compact(
+            mask.reshape(Qc, max_cand * capp), max_hits,
+            tags=jnp.repeat(cand, capp // _LANES, axis=1))
         hit_over = n_hits > max_hits
-        out = jnp.full((Qc, max_hits), -1, jnp.int32)
-        hq = jnp.broadcast_to(jnp.arange(Qc)[:, None], mask.shape)
-        okh = mask & (hpos < max_hits)
-        out = out.at[jnp.where(okh, hq, Qc), jnp.where(okh, hpos, 0)
-                     ].set(gid, mode="drop")
+        out = jnp.where(hpos >= 0, page * cap + hpos % capp, -1)
         return (out, n_hits, cand_over.astype(jnp.int32),
                 hit_over.astype(jnp.int32))
 

@@ -121,18 +121,43 @@ def _serving_specs(one_chip, d, cap, pages):
                          page_size=_spec(one_chip, (pages,)))
 
 
+@pytest.fixture(scope="module")
+def serving_programs(one_chip):
+    """(make, where) -> the compiled count (c64) or range (c64/h1024)
+    program, each compiled once for the tests that read it."""
+    done = {}
+
+    def get(make, where):
+        if (make, where) not in done:
+            d, cap, pages = WIDTHS[where]
+            fn = make(default_curve(d, default_K(d)),
+                      k_maxsplit=CFG.k_maxsplit, max_cand=CFG.max_cand,
+                      q_chunk=CFG.q_chunk, backend="pallas")
+            done[make, where] = jax.jit(fn).lower(
+                _serving_specs(one_chip, d, cap, pages),
+                _spec(one_chip, (64, d, 2))).compile()
+        return done[make, where]
+    return get
+
+
+SERVING = pytest.mark.parametrize("make", [make_query_fn, make_range_fn],
+                                  ids=["query_fn", "range_fn"])
+
+
 @pytest.mark.parametrize("where", sorted(WIDTHS))
-@pytest.mark.parametrize("make", [make_query_fn, make_range_fn],
-                         ids=["query_fn", "range_fn"])
-def test_serving_programs_compile(one_chip, make, where):
+@SERVING
+def test_serving_programs_compile(serving_programs, make, where):
     """The engines' jitted count / range programs with the Pallas window
     kernels, at the smoke's page counts and a 64-query bucket."""
-    d, cap, pages = WIDTHS[where]
-    fn = make(default_curve(d, default_K(d)), k_maxsplit=CFG.k_maxsplit,
-              max_cand=CFG.max_cand, q_chunk=CFG.q_chunk, backend="pallas")
-    compiled = jax.jit(fn).lower(_serving_specs(one_chip, d, cap, pages),
-                                 _spec(one_chip, (64, d, 2))).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(serving_programs(make, where))
+
+
+@pytest.mark.parametrize("where", sorted(WIDTHS))
+@SERVING
+def test_serving_programs_have_no_scatter(serving_programs, make, where):
+    """Candidate pages and hits are compacted by gathers: the TPU runs a
+    scatter about one update at a time, masked updates included."""
+    assert "scatter(" not in serving_programs(make, where).as_text()
 
 
 def test_distributed_program_compiles(topo):
